@@ -90,7 +90,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ProtocolConfig
 from repro.core import faults as faults_lib
@@ -129,10 +129,22 @@ class TpCtx:
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map — shared with the serving engine."""
-    from repro.launch.mesh import shard_map_compat
-    return shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs)
+    """shard_map without the replication checker: the round bodies
+    replicate server math by shared-seed computation, which it cannot
+    see."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _placed(jitted, mesh, in_specs):
+    """`jitted` with its arguments first put on the shardings of
+    `in_specs`. jit keys its compiles on the committed shardings of its
+    inputs: host-placed first-call arguments and the mesh-sharded
+    outputs fed back in later would otherwise compile the program twice.
+    A no-op for arguments already placed."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), in_specs,
+                             is_leaf=lambda s: isinstance(s, P))
+    return lambda *args: jitted(*jax.device_put(args, shardings))
 
 
 def _unstack_state(state, stacked_keys):
@@ -388,9 +400,10 @@ def _mesh_single_round(slice_round_fn: Callable, stacked_keys, metric_names,
                                           tp_axis=tp_axis, tp=tp),
             {name: rep for name in metric_names},
         )
-        return jax.jit(_shard_map(round_body, mesh=mesh,
-                                  in_specs=in_specs,
-                                  out_specs=out_specs))
+        return _placed(jax.jit(_shard_map(round_body, mesh=mesh,
+                                          in_specs=in_specs,
+                                          out_specs=out_specs)),
+                       mesh, in_specs)
 
     def run(state, data_stacked, weights, round_key):
         sig = (_tree_sig(state), _tree_sig(data_stacked))
@@ -656,10 +669,10 @@ def _mesh_rounds_scan(slice_round_fn: Callable, stacked_keys, metric_names,
         out_specs = (state_specs,
                      rules.tree_specs(sched_carry, rep),
                      out_round)
-        return jax.jit(
+        return _placed(jax.jit(
             _shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs),
-            donate_argnums=(0, 1))
+            donate_argnums=(0, 1)), mesh, in_specs)
 
     def run(state, sched_carry, data_stacked, key, start_round):
         sig = (_tree_sig(state), _tree_sig(sched_carry),

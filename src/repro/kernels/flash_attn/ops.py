@@ -2,12 +2,10 @@
 (b, s, heads, head_dim) <-> (BH, S, D) layout moves."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attn.kernel import flash_attention_pallas
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, n_kv_heads: int, causal: bool = True,
@@ -19,8 +17,7 @@ def flash_attention(q, k, v, *, n_kv_heads: int, causal: bool = True,
     by folding the group into the batch*kv axis on the query side — k/v
     are never repeated. Returns (b, s, n_heads, hd).
     """
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = interpret_mode(interpret)
     b, s, nh, hd = q.shape
     nkv = n_kv_heads
     g = nh // nkv

@@ -46,10 +46,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.core import quantize
 from repro.kernels.ring_wavg.kernel import BLOCK_N, ring_accum_pallas
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 # Chunks per hop: enough to overlap permute/accumulate without
 # shrinking blocks below useful DMA sizes at small payloads.
@@ -152,8 +151,7 @@ def ring_average_psum(local_params, local_weight, *, axis_names,
     (no-survivor round).
     """
     axis = _single_axis(axis_names)
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = interpret_mode(interpret)
     if not jax.tree_util.tree_leaves(local_params):
         return local_params
 

@@ -56,7 +56,8 @@ def _trimmed_kernel(w_ref, x_ref, o_ref, *, trim: int, k: int):
         is_mn = inc_mid & (small == mn)
         first = jnp.min(jnp.where(is_mn, ridx, k), axis=0, keepdims=True)
         rem_min = is_mn & (ridx == first)
-        inc = jnp.where(gate, inc & ~(rem_max | rem_min), inc)
+        # plain mask arithmetic: Mosaic has no select on boolean vectors
+        inc = inc & ~(gate & (rem_max | rem_min))
 
     wk = jnp.where(inc, jnp.broadcast_to(w, x.shape), 0.0)
     num = jnp.sum(wk * x, axis=0, keepdims=True)

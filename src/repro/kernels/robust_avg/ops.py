@@ -25,14 +25,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.robust_avg.kernel import trimmed_wavg_pallas
 from repro.kernels.wavg.kernel import BLOCK_N
 from repro.kernels.wavg import ops as wavg_ops
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 ROBUST_METHODS = ("trimmed_mean", "norm_clip", "krum")
 
@@ -64,8 +62,7 @@ def trimmed_average(x, w, *, trim: int, interpret: Optional[bool] = None):
     """Coordinate trimmed mean of x (K, N) with raw weights w (K,) ->
     (N,) f32. Pads N to BLOCK_N for the kernel and slices back (zero
     pad columns are harmless: the output tail is discarded)."""
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = interpret_mode(interpret)
     n = x.shape[1]
     pad = (-n) % BLOCK_N
     if pad:

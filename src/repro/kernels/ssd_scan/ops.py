@@ -3,12 +3,10 @@ group expansion, chunk padding, and the `scan_impl` hook consumed by
 `repro.nn.ssm.ssd_mixer_apply`."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, initial_state=None,
@@ -20,8 +18,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, initial_state=None,
     from zero state); callers resume via the reference decode step.
     """
     assert initial_state is None, "kernel path starts from zero state"
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = interpret_mode(interpret)
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     rep = h // g

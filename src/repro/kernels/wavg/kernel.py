@@ -23,7 +23,9 @@ def _wavg_kernel(w_ref, x_ref, o_ref):
     # w: (1, K) f32, x: (K, BN), out: (1, BN)
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.dot(w, x, preferred_element_type=jnp.float32
+    # HIGHEST: an f32 contraction on the MXU, never a bf16 pass
+    o_ref[...] = jnp.dot(w, x, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32
                          ).astype(o_ref.dtype)
 
 

@@ -5,10 +5,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.wavg.kernel import wavg_pallas, BLOCK_N
 from repro.kernels.wavg.ref import wavg_ref
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def weighted_average(x, w, *, interpret: bool | None = None):
@@ -23,8 +22,7 @@ def weighted_average(x, w, *, interpret: bool | None = None):
     path: `core.averaging.weighted_average_psum(impl="pallas")` calls
     this on the all-gathered flat payload, x = (K, N_total).
     """
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = interpret_mode(interpret)
     k = x.shape[0]
     flat = x.reshape(k, -1)
     n = flat.shape[1]

@@ -21,7 +21,7 @@ import jax  # noqa: E402
 
 from repro.configs import get_arch_config, list_archs, INPUT_SHAPES  # noqa: E402
 from repro.configs.base import MeshConfig  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch import steps as steps_mod  # noqa: E402
 from repro.launch import analysis  # noqa: E402
 
@@ -54,7 +54,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     if shape.kind == "train":
         kw.update(var_kw)
     step, args = steps_mod.build_step(cfg, shape, mesh, mesh_cfg, **kw)
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = step.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

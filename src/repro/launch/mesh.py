@@ -3,53 +3,17 @@
 Defined as FUNCTIONS so importing this module never touches jax device
 state (device count is locked at first jax init; dryrun.py sets
 XLA_FLAGS before importing anything).
-
-jax-version compatibility: `AxisType` / `make_mesh(axis_types=...)` /
-`jax.sharding.set_mesh` only exist in newer jax. On older releases
-(e.g. 0.4.x) the helpers here fall back to plain meshes and the Mesh
-context manager, which are semantically equivalent for this codebase
-(every step passes explicit NamedShardings).
 """
 from __future__ import annotations
 
 import jax
 
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
-def _auto(n):
-    if _HAS_AXIS_TYPES:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
-
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types when the installed jax has them."""
-    types = _auto(len(shape))
-    if types is not None:
-        return jax.make_mesh(shape, axes, axis_types=types)
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating `mesh`: jax.sharding.set_mesh on new
-    jax, the Mesh context manager on old jax."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map on new jax; jax.experimental.shard_map on 0.4.x
-    (where the replication-checker kwarg is `check_rep`, not `check_vma`).
-    The ONE compat wrapper — the round engine and the serving engine both
-    route manual-mesh bodies through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """jax.make_mesh with Auto axis types: every step passes explicit
+    NamedShardings, and the shard_map bodies name their axes."""
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def devices_error(n: int, context: str = "--layout mesh"):

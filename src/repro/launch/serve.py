@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import gan
 from repro.serving import Request, ServingEngine
 
@@ -77,6 +78,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch_config(args.arch)
     if args.reduced:

@@ -54,7 +54,8 @@ from repro.configs import get_arch_config, list_archs
 from repro.configs.base import MeshConfig, ProtocolConfig, ShapeConfig
 from repro.data import make_token_dataset
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 class AsyncCheckpointer:
@@ -258,6 +259,7 @@ def main():
                      "upload-corrupting faults (free-riders/byzantine); "
                      "use --avg-impl pallas")
 
+    enable_compile_cache()
     if args.distributed:
         jax.distributed.initialize()
 
@@ -375,7 +377,7 @@ def main():
                             "sim_wall": np.float64(wall_total),
                             "sched_carry": sched_carry}}
 
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         r = start_round
         for chunk in chunk_lengths(args.rounds - start_round, fuse):
             t0 = time.time()

@@ -71,8 +71,11 @@ def discriminator_apply(params, cfg: DCGANConfig, images):
     for layer in layers[1:-1]:
         x = nn.conv2d_apply(layer["conv"], x)
         x = jax.nn.leaky_relu(nn.batchnorm_apply(layer["bn"], x), 0.2)
-    x = nn.conv2d_apply(layers[-1]["conv"], x, stride=1, padding=0)
-    return x.reshape(x.shape[0])
+    # The last layer is a valid 4x4 conv over the 4x4 map with one output
+    # channel: a single output pixel, i.e. a contraction over (h, w, c).
+    w = layers[-1]["conv"]["w"]
+    assert x.shape[1:] == w.shape[:3], (x.shape, w.shape)
+    return jnp.einsum("bhwc,hwc->b", x, w[..., 0].astype(x.dtype))
 
 
 def gan_init(key, cfg: DCGANConfig):

@@ -16,7 +16,7 @@ classic Megatron f/g operators are expressed as custom-vjp pairs over
       when the next op needs the whole feature dim.
 
 Why custom_vjp instead of differentiating raw `lax.psum`: under
-`check_vma/check_rep=False` JAX transposes collectives mechanically,
+`check_vma=False` JAX transposes collectives mechanically,
 which silently DROPS the cross-rank dx sum of a column-parallel matmul
 (each rank's local AD only sees its own partial product). The pairs
 below pin the collective placement on both sides of the tape.

@@ -47,8 +47,7 @@ from repro.models.backbone import (init_decode_caches, cross_decode_kv,
                                    encoder_apply)
 from repro.serving import cache as paging
 from repro.sharding import rules
-from repro.launch.mesh import (make_host_mesh, shard_map_compat,
-                               tp_mesh_error, devices_error)
+from repro.launch.mesh import make_host_mesh, tp_mesh_error, devices_error
 
 
 @dataclasses.dataclass
@@ -253,9 +252,9 @@ class ServingEngine:
                         {k: P() for k in _DEC_FIELDS}]
             if chunk is not None:
                 in_specs.append({k: P() for k in _PF_FIELDS})
-            body = shard_map_compat(
+            body = jax.shard_map(
                 body, mesh=self._mesh, in_specs=tuple(in_specs),
-                out_specs=(rep(self.caches), P(), P()))
+                out_specs=(rep(self.caches), P(), P()), check_vma=False)
         return jax.jit(body, donate_argnums=(1,))
 
     def _get_step(self, chunk: Optional[int]):

@@ -67,15 +67,6 @@ def _rough_params(cfg: ArchConfig) -> int:
     return L * per_layer + 2 * cfg.vocab * d
 
 
-def _norm(axes):
-    """PartitionSpec entry: unwrap 1-tuples. Newer jax normalizes these
-    at construction; older (0.4.x) keeps the tuple, which breaks spec
-    equality even though GSPMD treats them identically."""
-    if isinstance(axes, tuple) and len(axes) == 1:
-        return axes[0]
-    return axes
-
-
 def _divisible(dim: int, mesh, axes) -> bool:
     if axes is None:
         return False
@@ -107,14 +98,14 @@ def _leaf_spec(path_names, leaf, mesh, plan: ParallelismPlan,
         if _divisible(shape[-1], mesh, tp):
             spec[-1] = tp
         if ndim >= 2 and fsdp_axes and _divisible(shape[-2], mesh, fsdp_axes):
-            spec[-2] = _norm(fsdp_axes)
+            spec[-2] = fsdp_axes
         return P(*spec)
     if name in _OUT_PROJ:
         spec = [None] * ndim
         if ndim >= 2 and _divisible(shape[-2], mesh, tp):
             spec[-2] = tp
         if fsdp_axes and _divisible(shape[-1], mesh, fsdp_axes):
-            spec[-1] = _norm(fsdp_axes)
+            spec[-1] = fsdp_axes
         return P(*spec)
     return P()
 
@@ -133,7 +124,7 @@ def stacked_specs(tree, mesh, plan: ParallelismPlan):
     """Specs for per-device stacked trees (leading K axis over dev_axes)."""
     inner = param_specs(jax.tree.map(lambda x: x[0], tree), mesh, plan)
     return jax.tree.map(
-        lambda s: P(_norm(plan.dev_axes), *s), inner,
+        lambda s: P(plan.dev_axes, *s), inner,
         is_leaf=lambda s: isinstance(s, P))
 
 
@@ -166,22 +157,22 @@ def param_specs_opt(opt_state, params, mesh, plan, *, fsdp: bool):
 
 def stacked_opt_specs(opt_state, params, mesh, plan):
     inner = param_specs(params, mesh, plan, fsdp=False)
-    stacked = jax.tree.map(lambda s: P(_norm(plan.dev_axes), *s), inner,
+    stacked = jax.tree.map(lambda s: P(plan.dev_axes, *s), inner,
                            is_leaf=lambda s: isinstance(s, P))
 
     def match(node):
         if isinstance(node, dict) and set(node) == set(("m", "v", "t")):
-            return {"m": stacked, "v": stacked, "t": P(_norm(plan.dev_axes))}
+            return {"m": stacked, "v": stacked, "t": P(plan.dev_axes)}
         if isinstance(node, dict) and set(node) == set(("mu",)):
             return {"mu": stacked}
-        return jax.tree.map(lambda _: P(_norm(plan.dev_axes)), node)
+        return jax.tree.map(lambda _: P(plan.dev_axes), node)
 
     return match(opt_state)
 
 
 def data_spec(plan: ParallelismPlan):
     """Token shards (K, n_k, seq): device axis over the paper's devices."""
-    return P(_norm(plan.dev_axes))
+    return P(plan.dev_axes)
 
 
 def enc_feats_spec(cfg: ArchConfig, mesh, plan: ParallelismPlan):
@@ -303,10 +294,10 @@ def _tp_entry_specs(tree, device_axes, stacked: bool, tp_axis: str,
         dim = _tp_path_dim(names, leaf.shape, tp)
         entries = [None] * ndim
         if stacked and ndim >= 1:
-            entries[0] = _norm(device_axes)
+            entries[0] = device_axes
         if dim is not None:
             entries[ndim + dim] = tp_axis
-        while entries and entries[-1] is None:   # P(None) != P() on 0.4.x
+        while entries and entries[-1] is None:   # P(None) != P()
             entries.pop()
         specs.append(P(*entries))
     return jax.tree_util.tree_unflatten(treedef, specs)
@@ -376,16 +367,16 @@ def cache_specs(cfg: ArchConfig, caches, batch: int, mesh,
         shape = leaf.shape  # (G, b, ...)
         spec = [None] * len(shape)
         if batch_shardable and len(shape) >= 2 and shape[1] == batch:
-            spec[1] = _norm(dev)
+            spec[1] = dev
         if name in ("k", "v", "pos", "valid") and len(shape) >= 3:
             # length dim is index 2 for k/v (G,b,L,kv,hd) and (G,b,L) for pos
             length = shape[2]
             if not batch_shardable:
                 axes = dev + (tp,)
                 if _divisible(length, mesh, axes):
-                    spec[2] = _norm(axes)
+                    spec[2] = axes
                 elif _divisible(length, mesh, dev):
-                    spec[2] = _norm(dev)
+                    spec[2] = dev
             elif name in ("k", "v") and _divisible(length, mesh, tp):
                 spec[2] = tp
         if name == "ssm" and len(shape) == 5:

@@ -396,6 +396,32 @@ class TestShardRoundBuilderMemo:
                      KEY, channel_cfg=chan, driver="host", layout="mesh")
         assert ta._round is tb._round
 
+    @pytest.mark.parametrize("driver", ["fused", "host"])
+    def test_mesh_trainer_compiles_once(self, driver):
+        """Later calls feed the mesh-sharded outputs of earlier ones
+        back in; the builders place every call's inputs on the same
+        shardings, so a second run compiles nothing."""
+        from jax import monitoring
+        pcfg = ProtocolConfig(n_devices=1, n_d=1, n_g=1, sample_size=2,
+                              server_sample_size=2)
+        t = Trainer(SPEC, pcfg, lambda k: dcgan.gan_init(k, CFG),
+                    DATA[:1], KEY, channel_cfg=ChannelConfig(n_devices=1,
+                                                             seed=3),
+                    driver=driver, layout="mesh")
+        t.run(2)
+        compiles = []
+
+        def listener(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        monitoring.register_event_duration_secs_listener(listener)
+        try:
+            t.run(2)
+        finally:
+            monitoring.unregister_event_duration_listener(listener)
+        assert compiles == []
+
 
 class TestMeshFusedEquivalence:
     """Satellite: the FULL layout x algorithm matrix — mesh-fused vs

@@ -8,6 +8,41 @@ import pytest
 KEY = jax.random.PRNGKey(0)
 
 
+class TestInterpretMode:
+    """The one platform probe every kernel wrapper asks at call time."""
+
+    def test_cpu_backend_interprets(self):
+        from repro.kernels import interpret_mode
+        assert jax.default_backend() == "cpu"
+        assert interpret_mode() is True
+
+    def test_tpu_compiles(self, monkeypatch):
+        from repro.kernels import interpret_mode
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert interpret_mode() is False
+
+    def test_explicit_choice_wins(self, monkeypatch):
+        from repro.kernels import interpret_mode
+        assert interpret_mode(False) is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert interpret_mode(True) is True
+
+    @pytest.mark.parametrize("platform", ["gpu", "cuda", "rocm", "metal"])
+    def test_unknown_platform_raises(self, monkeypatch, platform):
+        from repro.kernels import interpret_mode
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        with pytest.raises(RuntimeError, match=platform):
+            interpret_mode()
+
+    def test_wrapper_asks_at_call_time(self, monkeypatch):
+        """A wrapper reads the platform when it is called, not when its
+        module was imported: on an unknown platform it refuses."""
+        from repro.kernels.wavg.ops import weighted_average
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            weighted_average(jnp.ones((2, 8)), jnp.full((2,), 0.5))
+
+
 class TestWavg:
     @pytest.mark.parametrize("k,n", [(2, 64), (10, 2048), (16, 5000),
                                      (3, 1)])

@@ -62,13 +62,13 @@ def test_mini_dryrun_train_and_decode_lower_on_mesh():
 
         cfg = dataclasses.replace(get_arch_config('qwen3-1.7b').reduced(),
                                   vocab=512)
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ('data', 'model'))
         mesh_cfg = MeshConfig()
         train_shape = ShapeConfig('mini_train', 32, 8, 'train')
         step, args = steps_mod.build_train_step(cfg, train_shape, mesh,
                                                 mesh_cfg)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = step.lower(*args).compile()
             r = analyze_compiled(compiled, 8)
         assert r['roofline']['flops'] > 0
@@ -78,14 +78,14 @@ def test_mini_dryrun_train_and_decode_lower_on_mesh():
         dec_shape = ShapeConfig('mini_decode', 64, 8, 'decode')
         step, args = steps_mod.build_decode_step(cfg, dec_shape, mesh,
                                                  mesh_cfg)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = step.lower(*args).compile()
         print('decode lowers OK')
 
         pre_shape = ShapeConfig('mini_prefill', 64, 8, 'prefill')
         step, args = steps_mod.build_prefill_step(cfg, pre_shape, mesh,
                                                   mesh_cfg)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = step.lower(*args).compile()
         print('prefill lowers OK')
     """)
@@ -107,7 +107,7 @@ def test_mesh_layout_train_step_executes():
         from repro.core import protocol
         from repro.core.fedgan import make_fedgan_state
         from repro.launch import steps as steps_mod
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import gan as gan_model
 
         cfg = dataclasses.replace(get_arch_config('qwen3-1.7b').reduced(),
@@ -135,7 +135,7 @@ def test_mesh_layout_train_step_executes():
         assert jax.eval_shape(lambda: carry) == carry_abs
         tokens = jnp.zeros(tokens_abs.shape, tokens_abs.dtype)
         key = jax.random.PRNGKey(0)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             state, carry, out = step2(state, carry, tokens, key,
                                       jnp.int32(0))
             state, carry, out2 = step1(state, carry, tokens, key,
@@ -161,7 +161,7 @@ def test_mesh_layout_train_step_executes():
         assert all(l.shape[0] == 8 for l in gen_opt_leaves)
         carry = {'rr_cursor': jnp.int32(0),
                  'ewma_rate': jnp.ones(8, jnp.float32)}
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             fstate, carry, fout = fstep(fstate, carry, tokens, key,
                                         jnp.int32(0))
         assert fout['wallclock_s'].shape == (2,)
@@ -198,7 +198,7 @@ def test_mesh_layout_tp2_backbone_matches_tp1():
         from repro.configs.base import MeshConfig, ProtocolConfig, ShapeConfig
         from repro.core import protocol
         from repro.launch import steps as steps_mod
-        from repro.launch.mesh import make_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import gan as gan_model
         from repro.sharding import rules
 
@@ -235,10 +235,10 @@ def test_mesh_layout_tp2_backbone_matches_tp1():
                     'ewma_rate': jnp.ones(8, jnp.float32)}
         tokens = jnp.zeros(tokens_abs.shape, tokens_abs.dtype)
         key = jax.random.PRNGKey(0)
-        with use_mesh(mesh2):
+        with jax.sharding.set_mesh(mesh2):
             s2, c2, out2 = step2(jax.tree.map(jnp.copy, state),
                                  make_carry(), tokens, key, jnp.int32(0))
-        with use_mesh(mesh1):
+        with jax.sharding.set_mesh(mesh1):
             s1, c1, out1 = step1(jax.tree.map(jnp.copy, state),
                                  make_carry(), tokens, key, jnp.int32(0))
         np.testing.assert_array_equal(np.asarray(out1['mask']),
@@ -270,7 +270,7 @@ def test_protocol_round_executes_on_mesh():
         from repro.core import protocol
         from repro.models import dcgan
         from repro.models.specs import make_dcgan_spec
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
 
         cfg = DCGANConfig(nz=8, ngf=8, ndf=8, nc=1, image_size=16)
         spec = make_dcgan_spec(cfg)
@@ -284,7 +284,7 @@ def test_protocol_round_executes_on_mesh():
             jax.random.normal(key, (4, 8, 16, 16, 1)),
             NamedSharding(mesh, P('data')))
         w = jnp.full((4,), 4.0)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             new_state, metrics = jax.jit(
                 lambda s, d, ww, kk: protocol.gan_round(spec, pcfg, s, d,
                                                         ww, kk)

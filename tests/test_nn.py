@@ -130,6 +130,29 @@ class TestMLPConv:
         up = nn.conv_transpose2d_apply(pt, down)
         assert up.shape == (2, 16, 16, 3)
 
+    @pytest.mark.parametrize("image_size", [8, 64])
+    def test_dcgan_disc_head_is_the_valid_conv(self, image_size):
+        """The DCGAN discriminator's last layer (a valid 4x4 conv to one
+        channel, written as a contraction) equals the conv it replaces."""
+        from repro.configs.dcgan import DCGANConfig
+        from repro.models import dcgan
+        cfg = DCGANConfig(nz=8, ngf=8, ndf=8, nc=3, image_size=image_size)
+        params = dcgan.discriminator_init(KEY, cfg)
+        img = jax.random.normal(KEY, (3, image_size, image_size, 3))
+        x = jax.nn.leaky_relu(
+            nn.conv2d_apply(params["layers"][0]["conv"], img), 0.2)
+        for layer in params["layers"][1:-1]:
+            x = nn.conv2d_apply(layer["conv"], x)
+            x = jax.nn.leaky_relu(nn.batchnorm_apply(layer["bn"], x), 0.2)
+        with jax.default_matmul_precision("highest"):
+            conv = nn.conv2d_apply(params["layers"][-1]["conv"], x,
+                                   stride=1, padding=0)
+            out = dcgan.discriminator_apply(params, cfg, img)
+        assert conv.shape == (3, 1, 1, 1)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(conv).reshape(3), rtol=1e-5,
+                                   atol=1e-5)
+
 
 class TestTensorParallel:
     """Megatron column/row-parallel paths (nn/tp.py, linear, mlp) must

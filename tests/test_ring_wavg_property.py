@@ -30,7 +30,7 @@ import numpy as np
 
 from repro.core import quantize
 from repro.core.averaging import weighted_average_psum
-from repro.kernels.ring_wavg.kernel import BLOCK_N, ring_accum_pallas
+from repro.kernels.ring_wavg.kernel import BLOCK_N, ROWS, ring_accum_pallas
 from repro.kernels.ring_wavg.ops import (DEFAULT_CHUNKS, _chunk_bounds,
                                          ring_average_psum,
                                          ring_wire_bytes_per_rank)
@@ -228,6 +228,21 @@ class TestRingAccumKernel:
                 np.dtype(dtype))
         out = ring_accum_pallas(jnp.asarray(acc),
                                 jnp.asarray(q, dtype),
+                                jnp.asarray(coef), interpret=True)
+        expect = acc + coef[:, None] * q.astype(np.float32)
+        np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6,
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.int16, jnp.float32])
+    def test_accumulate_ragged_grid_matches_numpy(self, dtype):
+        """More wire blocks than one grid step takes, with a ragged last
+        step: every row is accumulated once and only once."""
+        rng = np.random.default_rng(3)
+        nb = 2 * ROWS + 5
+        acc = rng.standard_normal((nb, BLOCK_N)).astype(np.float32)
+        coef = rng.standard_normal(nb).astype(np.float32)
+        q = rng.integers(-1000, 1000, (nb, BLOCK_N)).astype(np.dtype(dtype))
+        out = ring_accum_pallas(jnp.asarray(acc), jnp.asarray(q),
                                 jnp.asarray(coef), interpret=True)
         expect = acc + coef[:, None] * q.astype(np.float32)
         np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6,
