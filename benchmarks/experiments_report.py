@@ -1,7 +1,6 @@
 """Generate EXPERIMENTS.md sections from results/ artifacts.
 
   §Dry-run      from results/dryrun/*.json (memory / collective schedule)
-  §Roofline     three-term table + dominant bottleneck + useful ratio
   §Paper-validation  from results/bench/*.json curves
   §Perf         from results/perf/*.json hillclimb records
 """
@@ -10,15 +9,9 @@ from __future__ import annotations
 import glob
 import json
 import os
-import sys
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-from benchmarks.roofline_report import load_rows  # noqa: E402
 
 
 def fmt_dryrun_section():
-    rows = load_rows()
     out = ["## §Dry-run\n"]
     out.append("Every (architecture × input shape) lowered AND compiled on "
                "the single-pod 16×16 mesh and the 2×16×16 multi-pod mesh "
@@ -42,27 +35,6 @@ def fmt_dryrun_section():
         peak = (d["memory"].get("peak_bytes") or 0) / 1e9
         out.append(f"| {d['arch']} | {d['shape']} | {d['mesh']} | "
                    f"{peak:.2f} | {cstr} |")
-    return "\n".join(out)
-
-
-def fmt_roofline_section():
-    rows = load_rows()
-    out = ["## §Roofline\n"]
-    out.append("Terms per the spec: compute = FLOPs/(chips·197 TF/s), "
-               "memory = bytes/(chips·819 GB/s), collective = "
-               "coll_bytes/(chips·50 GB/s). FLOPs/bytes are loop-aware "
-               "HLO counts (XLA's cost_analysis counts while bodies once "
-               "— see hlo_costs.py); MODEL_FLOPS = 6·N_active·D (train) "
-               "or 2·N_active·D (serve); useful = MODEL_FLOPS/HLO_FLOPs.\n")
-    out.append("| arch | shape | mesh | compute_s | memory_s | "
-               "collective_s | dominant | useful | peak GB |")
-    out.append("|---|---|---|---|---|---|---|---|---|")
-    for r in rows:
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} | "
-            f"{r['compute_s']:.3e} | {r['memory_s']:.3e} | "
-            f"{r['collective_s']:.3e} | {r['dominant']} | "
-            f"{r['useful_ratio']:.2f} | {r['peak_gb']:.2f} |")
     return "\n".join(out)
 
 
@@ -112,8 +84,6 @@ def fmt_perf_section():
 
 def main():
     print(fmt_dryrun_section())
-    print()
-    print(fmt_roofline_section())
     print()
     print(fmt_bench_section())
     print()

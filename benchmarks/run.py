@@ -1,6 +1,5 @@
-"""Benchmark driver: one benchmark per paper figure + kernel microbench
-+ the roofline table from the dry-run. Prints ``name,us_per_call,derived``
-CSV rows.
+"""Benchmark driver: the fused-driver bench and one benchmark per paper
+figure, on the CPU. Prints ``name,us_per_call,derived`` CSV rows.
 
 Scale via env: REPRO_BENCH_ROUNDS (default 12), REPRO_BENCH_FULL=1 for
 the paper-faithful 64x64 DCGAN / n_d=n_g=5 / m_k=128 settings.
@@ -15,9 +14,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 def main() -> None:
     print("name,us_per_call,derived")
-    from benchmarks import kernels_bench
-    kernels_bench.main()
-
     from benchmarks import driver_bench
     driver_bench.main()
 
@@ -27,10 +23,6 @@ def main() -> None:
     fig4_devices.main()
     fig5_fedgan.main()
     fig6_scheduling.main()
-
-    print()
-    from benchmarks import roofline_report
-    roofline_report.main()
 
 
 if __name__ == "__main__":

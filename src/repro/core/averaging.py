@@ -50,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import stages
 from repro.kernels.robust_avg.ops import ROBUST_METHODS, RobustConfig
 
 
@@ -90,6 +91,7 @@ def _apply_fallback(avg, fallback, total):
         avg, fallback)
 
 
+@stages.stage(stages.A2_AVERAGE)
 def weighted_average(stacked_params, weights, *, impl: str = "jnp",
                      robust: Optional[RobustConfig] = None,
                      interpret=None, fallback=None):
@@ -131,6 +133,7 @@ def weighted_average(stacked_params, weights, *, impl: str = "jnp",
                            jnp.sum(weights.astype(jnp.float32)))
 
 
+@stages.stage(stages.A2_AVERAGE)
 def weighted_average_psum(local_params, local_weight, *, axis_names,
                           impl: str = "jnp", robust: Optional[RobustConfig] = None,
                           interpret=None, fallback=None,

@@ -84,7 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ProtocolConfig
-from repro.core import protocol, fedgan, shard_round
+from repro.core import protocol, fedgan, shard_round, stages
 from repro.core import faults as faults_lib
 from repro.core.channel import ChannelConfig, ChannelSimulator, round_wallclock
 from repro.core.faults import FaultConfig
@@ -170,6 +170,10 @@ def mesh_algorithm(name: str) -> _Algorithm:
         raise ValueError(f"layout='mesh' supports algorithms "
                          f"{MESH_ALGORITHMS} (got {name!r})")
     return algo
+
+
+# the per-round outputs of a fused chunk that the host reads back
+_READBACK = ("metrics", "wallclock_s", "mask", "fid", "fid_eval")
 
 
 @dataclasses.dataclass
@@ -469,40 +473,52 @@ class Trainer:
                                            fid_fn is not None)
         for chunk in chunks:
             start = self._round_index
-            fn = self._chunk_fn(chunk, eval_every if in_scan_fid else 0,
-                                fid_fn if in_scan_fid else None)
-            self.state, self._sched_carry, out = fn(
-                self.state, self._sched_carry, self.data, self.key,
-                jnp.int32(start))
-            metrics = {k: np.asarray(v) for k, v in out["metrics"].items()}
-            walls = np.asarray(out["wallclock_s"])
-            masks = np.asarray(out["mask"])
-            fids = np.asarray(out["fid"]) if "fid" in out else None
-            fid_evals = (np.asarray(out["fid_eval"])
-                         if "fid_eval" in out else None)
-            for i in range(chunk):
-                t = start + i
-                self._clock += float(walls[i])
-                fid = None
-                if fids is not None:
-                    # explicit eval mask: a NaN FID on an eval round is
-                    # reported as NaN, exactly like the host loop
-                    if fid_evals[i]:
-                        fid = float(fids[i])
-                elif (fid_fn is not None and eval_every
-                        and (t + 1) % eval_every == 0):
-                    fid = float(fid_fn(self.state["gen"],
-                                       jax.random.fold_in(self.key,
-                                                          10_000 + t)))
-                rec = RoundRecord(
-                    t, float(walls[i]), self._clock,
-                    {k: float(v[i]) for k, v in metrics.items()}, fid,
-                    mask=masks[i])
-                self.history.append(rec)
-                if verbose:
-                    self._print_record(rec)
+            with stages.dispatch_span(start):
+                with stages.span(stages.ENQUEUE):
+                    fn = self._chunk_fn(chunk,
+                                        eval_every if in_scan_fid else 0,
+                                        fid_fn if in_scan_fid else None)
+                    self.state, self._sched_carry, out = fn(
+                        self.state, self._sched_carry, self.data, self.key,
+                        jnp.int32(start))
+                # the one place the host waits for the device
+                with stages.span(stages.WAIT):
+                    jax.block_until_ready(out)
+                with stages.span(stages.READBACK):
+                    out = {k: jax.tree.map(np.asarray, out[k])
+                           for k in _READBACK if k in out}
+                with stages.span(stages.RECORDS):
+                    self._record_chunk(start, chunk, out, eval_every,
+                                       fid_fn, verbose)
             self._round_index += chunk
         return self.history
+
+    def _record_chunk(self, start: int, chunk: int, out, eval_every: int,
+                      fid_fn: Optional[Callable], verbose: bool):
+        """Append the chunk's `RoundRecord`s from its host-side outputs
+        (the host-eval fallback computes FID on eval rounds here)."""
+        metrics, walls = out["metrics"], out["wallclock_s"]
+        fids = out.get("fid")
+        for i in range(chunk):
+            t = start + i
+            self._clock += float(walls[i])
+            fid = None
+            if fids is not None:
+                # explicit eval mask: a NaN FID on an eval round is
+                # reported as NaN, exactly like the host loop
+                if out["fid_eval"][i]:
+                    fid = float(fids[i])
+            elif (fid_fn is not None and eval_every
+                    and (t + 1) % eval_every == 0):
+                fid = float(fid_fn(self.state["gen"],
+                                   jax.random.fold_in(self.key, 10_000 + t)))
+            rec = RoundRecord(
+                t, float(walls[i]), self._clock,
+                {k: float(v[i]) for k, v in metrics.items()}, fid,
+                mask=out["mask"][i])
+            self.history.append(rec)
+            if verbose:
+                self._print_record(rec)
 
     # ------------------------------------------------------------------
     # host driver — one round per dispatch (the oracle)

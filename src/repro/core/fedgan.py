@@ -22,13 +22,14 @@ import jax.numpy as jnp
 
 from repro.configs.base import ProtocolConfig
 from repro.core import faults as faults_lib
-from repro.core import losses, quantize
+from repro.core import losses, quantize, stages
 from repro.core.averaging import weighted_average, broadcast_like
 from repro.core.protocol import (GanModelSpec, rounds_scan,
                                  _SALT_SHARED_Z, _SALT_DATA)
 from repro.optim import make_optimizer, apply_updates
 
 
+@stages.stage(stages.A1_LOCAL)
 def fedgan_device_update(spec: GanModelSpec, pcfg: ProtocolConfig,
                          gen0, disc0, gen_opt, disc_opt, data_local,
                          round_key, dev_index):

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from repro.configs.base import ProtocolConfig
 from repro.core import faults as faults_lib
 from repro.core import jax_channel, jax_scheduling, losses, quantize
+from repro.core import stages
 from repro.core.averaging import weighted_average, broadcast_like
 from repro.optim import make_optimizer, apply_updates
 from repro.optim.optimizers import tree_add
@@ -132,6 +133,7 @@ def make_train_state(key, init_fn, pcfg: ProtocolConfig, n_devices: int):
 # Algorithm 1 — device k's update
 # ---------------------------------------------------------------------------
 
+@stages.stage(stages.A1_LOCAL)
 def device_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
                   disc_params, disc_opt, data_local, round_key, dev_index):
     """n_d mini-batch steps ascending eq (2) on the LOCAL data shard.
@@ -170,6 +172,7 @@ def device_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
     return disc, opt_state, objs[-1]
 
 
+@stages.stage(stages.A1_LOCAL)
 def devices_round_hoisted(spec: GanModelSpec, pcfg: ProtocolConfig,
                           gen_params, disc_stacked, disc_opt_stacked,
                           data_stacked, round_key):
@@ -221,6 +224,7 @@ def devices_round_hoisted(spec: GanModelSpec, pcfg: ProtocolConfig,
 # Algorithm 3 — server generator update
 # ---------------------------------------------------------------------------
 
+@stages.stage(stages.A3_SERVER)
 def server_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
                   gen_opt, disc_params, round_key):
     """n_g steps descending eq (1) against the given discriminator.

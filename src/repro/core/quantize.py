@@ -19,6 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import stages
+
 # Salt separating the quantization stream from the shared-noise /
 # data-sampling streams of core.protocol.
 _SALT_QUANT = 0x0b175
@@ -81,6 +83,7 @@ def roundtrip(key, tree, bits: int = 16):
     return jax.tree.map(lambda d, x: d.astype(x.dtype), deq, tree)
 
 
+@stages.stage(stages.UPLINK)
 def roundtrip_tp(key, tree, bits: int = 16, *, tp_axis=None, tp: int = 1,
                  shard_dims=None):
     """`roundtrip` for a TENSOR-PARALLEL shard of the upload payload.
@@ -159,6 +162,7 @@ def device_uplink_key(round_key, dev_index):
                               dev_index)
 
 
+@stages.stage(stages.UPLINK)
 def roundtrip_stacked(round_key, stacked_tree, bits: int = 16):
     """Per-device quantize-dequantize of a pytree with leading axis K
     (Step 3: every scheduled device quantizes its OWN upload with its

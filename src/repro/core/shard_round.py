@@ -95,7 +95,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ProtocolConfig
 from repro.core import faults as faults_lib
 from repro.core import fedgan as fedgan_mod
-from repro.core import jax_channel, quantize
+from repro.core import jax_channel, quantize, stages
 from repro.core.protocol import (GanModelSpec, count_params, device_update,
                                  schedule_and_time, server_update,
                                  uplink_payload_bits)
@@ -144,7 +144,13 @@ def _placed(jitted, mesh, in_specs):
     A no-op for arguments already placed."""
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), in_specs,
                              is_leaf=lambda s: isinstance(s, P))
-    return lambda *args: jitted(*jax.device_put(args, shardings))
+
+    def run(*args):
+        with stages.span(stages.PLACE):
+            args = jax.device_put(args, shardings)
+        return jitted(*args)
+
+    return run
 
 
 def _unstack_state(state, stacked_keys):
@@ -197,6 +203,7 @@ def _tp_ctx(payload_fn, state, tp_axis, tp) -> Optional[TpCtx]:
     return TpCtx(tp_axis, tp, rules.tp_tree_dims(payload_fn(state), tp))
 
 
+@stages.stage(stages.UPLINK)
 def _quantize_uplink(tp_ctx: Optional[TpCtx], key, payload, bits: int):
     """The Step-3 uplink quantizer, per TP regime: the plain worker
     stream at tp=1, the worker-global reconstructed stream per shard
@@ -406,9 +413,10 @@ def _mesh_single_round(slice_round_fn: Callable, stacked_keys, metric_names,
                        mesh, in_specs)
 
     def run(state, data_stacked, weights, round_key):
-        sig = (_tree_sig(state), _tree_sig(data_stacked))
-        fn = _sig_cache_get(cache, sig,
-                            lambda: build(state, data_stacked))
+        with stages.span(stages.SIGNATURE):
+            sig = (_tree_sig(state), _tree_sig(data_stacked))
+            fn = _sig_cache_get(cache, sig,
+                                lambda: build(state, data_stacked))
         return fn(state, data_stacked, weights, round_key)
 
     return run
@@ -675,10 +683,11 @@ def _mesh_rounds_scan(slice_round_fn: Callable, stacked_keys, metric_names,
             donate_argnums=(0, 1)), mesh, in_specs)
 
     def run(state, sched_carry, data_stacked, key, start_round):
-        sig = (_tree_sig(state), _tree_sig(sched_carry),
-               _tree_sig(data_stacked))
-        fn = _sig_cache_get(
-            cache, sig, lambda: build(state, sched_carry, data_stacked))
+        with stages.span(stages.SIGNATURE):
+            sig = (_tree_sig(state), _tree_sig(sched_carry),
+                   _tree_sig(data_stacked))
+            fn = _sig_cache_get(
+                cache, sig, lambda: build(state, sched_carry, data_stacked))
         return fn(state, sched_carry, data_stacked, key, start_round)
 
     return run
