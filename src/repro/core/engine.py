@@ -14,7 +14,8 @@ that is meaningful:
 EXECUTION LAYOUT — how the paper's K devices map onto hardware:
 
   layout="stacked" (default) — devices are a stacked leading axis on
-      one logical device; vmap runs the local updates and the averaging
+      one logical device; the proposed protocol's local updates run one
+      device after another (FedGAN's are vmapped), and the averaging
       is a weighted mean over the axis (GSPMD lowers it to the
       all-reduce when the axis is mesh-sharded through launch/steps.py).
   layout="mesh" — devices are mesh slices under `jax.shard_map` with

@@ -14,10 +14,21 @@ One communication round (Section II-B, Section III):
           global discriminator.
 
 `gan_round` is a pure jittable function: the paper's K devices appear as
-a stacked leading axis, so the SAME code runs (a) on CPU for the
-paper-scale experiments and (b) under pjit on the production mesh where
-the stacked axis is sharded over ("pod","data") and Algorithm 2's
-weighted mean lowers to the ICI all-reduce (DESIGN.md §2).
+a stacked leading axis, so the SAME code runs (a) on one chip or the CPU
+for the paper-scale experiments and (b) under pjit on the production
+mesh where the stacked axis is sharded over ("pod","data") and Algorithm
+2's weighted mean lowers to the ICI all-reduce (DESIGN.md §2).
+
+How Step 2 runs over the stacked axis depends on that sharding alone:
+- unsharded (no `constrain_stacked`, every `Trainer` round): one device
+  after another (`devices_round`, a `lax.map`), each on plain
+  convolutions of batch m_k, with the shared fake batches made once per
+  local step for all of them;
+- sharded (`constrain_stacked`, launch/steps.py's GSPMD path):
+  `jax.vmap(device_update)`, so each slice of the sharded axis computes
+  only its own devices; the batching makes every discriminator
+  convolution a K-way grouped one.
+`hoist_fakes` keeps the vmap too (`devices_round_hoisted`).
 
 The model is abstracted by `GanModelSpec`, so DCGAN (the paper's
 experiment) and every assigned backbone-GAN use one protocol
@@ -133,29 +144,56 @@ def make_train_state(key, init_fn, pcfg: ProtocolConfig, n_devices: int):
 # Algorithm 1 — device k's update
 # ---------------------------------------------------------------------------
 
+def _shared_z_key(round_key, j):
+    """Local/server step j's key on the SHARED noise stream."""
+    return jax.random.fold_in(jax.random.fold_in(round_key, _SALT_SHARED_Z), j)
+
+
+@stages.stage(stages.A1_LOCAL)
+def shared_fakes(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
+                 round_key):
+    """The n_d fake batches of Algorithm 1, stacked (n_d, m_k, ...): G at
+    the round-start theta on the shared stream, the same for every
+    device, so one generator forward per local step serves them all."""
+    return jax.lax.map(
+        lambda j: spec.gen_apply(gen_params, spec.sample_z(
+            _shared_z_key(round_key, j), pcfg.sample_size)),
+        jnp.arange(pcfg.n_d))
+
+
 @stages.stage(stages.A1_LOCAL)
 def device_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
-                  disc_params, disc_opt, data_local, round_key, dev_index):
+                  disc_params, disc_opt, data_local, round_key, dev_index,
+                  fakes=None, stacked=False):
     """n_d mini-batch steps ascending eq (2) on the LOCAL data shard.
 
-    data_local: pytree with leading axis n_k (the device's private data).
+    data_local: pytree with leading axis n_k (the device's private data);
+    with `stacked`, every device's shards (K, n_k, ...), of which the
+    steps read row `dev_index`'s with one gather per step.
     Fresh samples each step (Algorithm 1 line 5): m_k indices drawn with
     replacement from the local shard; noise from the SHARED stream.
+    fakes: the `shared_fakes` batches, (n_d, m_k, ...); None makes them
+    here, one generator forward per step.
     """
-    n_local = jax.tree_util.tree_leaves(data_local)[0].shape[0]
+    n_local = jax.tree_util.tree_leaves(data_local)[0].shape[int(stacked)]
     m = pcfg.sample_size
     opt = make_optimizer(pcfg.optimizer, pcfg.lr_d)
 
+    def rows(a, idx):
+        return a[dev_index, idx] if stacked else jnp.take(a, idx, axis=0)
+
     def one_step(carry, j):
         disc, opt_state = carry
-        kz = jax.random.fold_in(jax.random.fold_in(round_key, _SALT_SHARED_Z), j)
+        kz = _shared_z_key(round_key, j)
         kx = jax.random.fold_in(
             jax.random.fold_in(jax.random.fold_in(round_key, _SALT_DATA),
                                dev_index), j)
         idx = jax.random.randint(kx, (m,), 0, n_local)
-        x = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), data_local)
-        z = spec.sample_z(kz, m)
-        fake = spec.gen_apply(gen_params, z)      # round-start theta
+        x = jax.tree.map(lambda a: rows(a, idx), data_local)
+        if fakes is None:                         # round-start theta
+            fake = spec.gen_apply(gen_params, spec.sample_z(kz, m))
+        else:
+            fake = fakes[j]
 
         def neg_obj(phi, x_mb, fake_mb):
             return -losses.disc_objective(spec.disc_real(phi, x_mb),
@@ -170,6 +208,28 @@ def device_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
     (disc, opt_state), objs = jax.lax.scan(
         one_step, (disc_params, disc_opt), jnp.arange(pcfg.n_d))
     return disc, opt_state, objs[-1]
+
+
+@stages.stage(stages.A1_LOCAL)
+def devices_round(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
+                  disc_params, disc_opt_stacked, data_stacked, round_key):
+    """Algorithm 1 for ALL devices, one device after another.
+
+    Every device starts from the global `disc_params` and runs its n_d
+    steps on plain convolutions of batch m_k, reading its m_k rows of
+    `data_stacked` (K, n_k, ...) with one gather a step; the shared fake
+    batches are made once for all of them (`shared_fakes`). The same
+    math as `jax.vmap(device_update)`, whose batching turns each
+    discriminator convolution into one K-way grouped convolution.
+    Returns the stacked (discs, opt states, objectives).
+    """
+    n_devices = jax.tree_util.tree_leaves(data_stacked)[0].shape[0]
+    fakes = shared_fakes(spec, pcfg, gen_params, round_key)
+    return jax.lax.map(
+        lambda xs: device_update(spec, pcfg, gen_params, disc_params, xs[0],
+                                 data_stacked, round_key, xs[1], fakes,
+                                 True),
+        (disc_opt_stacked, jnp.arange(n_devices)))
 
 
 @stages.stage(stages.A1_LOCAL)
@@ -191,8 +251,7 @@ def devices_round_hoisted(spec: GanModelSpec, pcfg: ProtocolConfig,
 
     def one_step(carry, j):
         discs, opts = carry
-        kz = jax.random.fold_in(jax.random.fold_in(round_key, _SALT_SHARED_Z), j)
-        z = spec.sample_z(kz, m)
+        z = spec.sample_z(_shared_z_key(round_key, j), m)
         fake = spec.gen_apply(gen_params, z)      # once, for every device
 
         def one_device(disc, opt_state, data_local, dev_index):
@@ -235,8 +294,7 @@ def server_update(spec: GanModelSpec, pcfg: ProtocolConfig, gen_params,
 
     def one_step(carry, j):
         gen, opt_state = carry
-        kz = jax.random.fold_in(jax.random.fold_in(round_key, _SALT_SHARED_Z), j)
-        z = spec.sample_z(kz, M)
+        z = spec.sample_z(_shared_z_key(round_key, j), M)
 
         def obj(theta, z_mb):
             fake = spec.gen_apply(theta, z_mb)
@@ -277,26 +335,35 @@ def gan_round(spec: GanModelSpec, pcfg: ProtocolConfig, state, data_stacked,
     Returns (new_state, metrics).
     """
     n_devices = weights.shape[0]
-    disc_stacked = broadcast_like(state["disc"], n_devices)  # Step 5 (prev)
-    if constrain_stacked is not None:
-        # pjit path: pin the per-device replicas to the device mesh axes so
-        # GSPMD keeps Algorithm 1 embarrassingly parallel.
-        disc_stacked = constrain_stacked(disc_stacked)
-
-    # Step 2 — Algorithm 1 on every device slice (vmapped; on the pod mesh
-    # the stacked axis is sharded so each slice computes only its own).
-    if pcfg.hoist_fakes:
-        new_discs, new_disc_opt, disc_objs = devices_round_hoisted(
-            spec, pcfg, state["gen"], disc_stacked, state["disc_opt"],
-            data_stacked, round_key)
+    if pcfg.hoist_fakes or constrain_stacked is not None:
+        # Step 2 — Algorithm 1 on every device slice, vmapped: each
+        # device's discriminator convolutions batch into one K-way grouped
+        # convolution. On the pod mesh (`constrain_stacked`) the stacked
+        # axis is sharded, so each slice computes only its own.
+        disc_stacked = broadcast_like(state["disc"], n_devices)  # Step 5
+        if constrain_stacked is not None:
+            # pjit path: pin the per-device replicas to the device mesh
+            # axes so GSPMD keeps Algorithm 1 embarrassingly parallel.
+            disc_stacked = constrain_stacked(disc_stacked)
+        if pcfg.hoist_fakes:
+            new_discs, new_disc_opt, disc_objs = devices_round_hoisted(
+                spec, pcfg, state["gen"], disc_stacked, state["disc_opt"],
+                data_stacked, round_key)
+        else:
+            dev_fn = jax.vmap(
+                lambda d, o, x, i: device_update(spec, pcfg, state["gen"],
+                                                 d, o, x, round_key, i),
+                in_axes=(0, 0, 0, 0))
+            new_discs, new_disc_opt, disc_objs = dev_fn(
+                disc_stacked, state["disc_opt"], data_stacked,
+                jnp.arange(n_devices))
     else:
-        dev_fn = jax.vmap(
-            lambda d, o, x, i: device_update(spec, pcfg, state["gen"], d, o,
-                                             x, round_key, i),
-            in_axes=(0, 0, 0, 0))
-        new_discs, new_disc_opt, disc_objs = dev_fn(
-            disc_stacked, state["disc_opt"], data_stacked,
-            jnp.arange(n_devices))
+        # Step 2 — Algorithm 1 one device at a time, on plain convolutions
+        # of batch m_k, each from the global discriminator (Step 5 of the
+        # previous round); nothing is broadcast.
+        new_discs, new_disc_opt, disc_objs = devices_round(
+            spec, pcfg, state["gen"], state["disc"], state["disc_opt"],
+            data_stacked, round_key)
 
     # Step 3 — each device quantizes its upload (paper Section IV,
     # 16 bits/param by default; >=32 bits is the float32 identity).

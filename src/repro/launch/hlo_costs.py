@@ -224,6 +224,24 @@ class HloModule:
         return best
 
     # ------------------------------------------------------------------
+    def runs(self) -> dict[str, int]:
+        """How many times each computation runs per call of the entry:
+        as often as its callers, times the trip count of the while loop
+        whose body it is."""
+        out: dict[str, int] = defaultdict(int)
+
+        def visit(name: str, n: int):
+            out[name] += n
+            for ins in self.computations.get(name, []):
+                _, _, _, called, trip = self._instr_costs(ins)
+                if called in self.computations:
+                    visit(called, n * trip)
+
+        assert self.entry, "no ENTRY computation found"
+        visit(self.entry, 1)
+        return dict(out)
+
+    # ------------------------------------------------------------------
     def totals(self):
         memo: dict[str, tuple] = {}
 

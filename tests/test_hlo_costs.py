@@ -44,6 +44,26 @@ def test_nested_scans_multiply():
     assert costs["flops"] == pytest.approx(expected, rel=0.01)
 
 
+def test_runs_count_each_computation_per_call():
+    """A dot in the inner of two nested scans runs outer x inner times."""
+    def f(x, w):
+        def outer(c, _):
+            def inner(ci, _):
+                return ci @ w, None
+            ci, _ = jax.lax.scan(inner, c, None, length=3)
+            return ci, None
+        y, _ = jax.lax.scan(outer, x, None, length=5)
+        return y.sum()
+
+    module = HloModule(jax.jit(f).lower(jnp.zeros((16, 16)),
+                                        jnp.zeros((16, 16))).compile()
+                       .as_text())
+    runs = module.runs()
+    dots = [runs[name] for name, instrs in module.computations.items()
+            for ins in instrs if ins.op == "dot"]
+    assert dots == [15]
+    assert runs[module.entry] == 1
+
 def test_straightline_dot():
     compiled = jax.jit(lambda a, b: a @ b).lower(
         jnp.zeros((8, 32)), jnp.zeros((32, 4))).compile()

@@ -158,6 +158,53 @@ class TestRound:
             jax.random.key_data(kz_server), jax.random.key_data(kz_device))
 
 
+class TestStackedAlgorithm1:
+    @pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+    def test_devices_round_equals_per_worker_oracle(self, optimizer):
+        """The stacked layout's Algorithm 1 (one worker after another, the
+        shared fakes made once) against `device_update` called worker by
+        worker on its own shard: discs, opt states and objectives to f32
+        round-off."""
+        pcfg = ProtocolConfig(n_devices=3, n_d=3, n_g=1, sample_size=4,
+                              server_sample_size=4, lr_d=1e-2,
+                              optimizer=optimizer)
+        state = make_state(pcfg, 3)
+        state["disc_opt"] = jax.tree.map(
+            lambda a: a + 0.01 * jnp.arange(3.0).reshape((3,) + (1,) * (
+                a.ndim - 1)).astype(a.dtype), state["disc_opt"])
+        data = make_data(3)
+        got = jax.jit(lambda s, d: protocol.devices_round(
+            SPEC, pcfg, s["gen"], s["disc"], s["disc_opt"], d, KEY))(
+                state, data)
+
+        def oracle(s, d):
+            one = jax.jit(lambda o, x, k: protocol.device_update(
+                SPEC, pcfg, s["gen"], s["disc"], o, x, KEY, k))
+            outs = [one(jax.tree.map(lambda a: a[k], s["disc_opt"]), d[k],
+                        jnp.int32(k)) for k in range(3)]
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+
+        want = oracle(state, data)
+        leaves_close(got, want)
+        assert any(float(jnp.abs(a - b).max()) > 0 for a, b in zip(
+            jax.tree_util.tree_leaves(got[0]),
+            jax.tree_util.tree_leaves(broadcast_like(state["disc"], 3))))
+
+    def test_sharded_worker_axis_round_equals_the_loop(self):
+        """`constrain_stacked` keeps the vmapped Algorithm 1; its round
+        equals the unsharded round's loop to f32 round-off."""
+        pcfg = ProtocolConfig(n_devices=3, n_d=2, n_g=2, sample_size=4,
+                              server_sample_size=4, lr_d=1e-2, lr_g=1e-2,
+                              quantize_bits=32)
+        state = make_state(pcfg, 3)
+        data = make_data(3)
+        w = jnp.asarray([4.0, 0.0, 4.0])
+        loop = protocol.gan_round(SPEC, pcfg, state, data, w, KEY)
+        vmapped = protocol.gan_round(SPEC, pcfg, state, data, w, KEY,
+                                     constrain_stacked=lambda t: t)
+        leaves_close(loop, vmapped)
+
+
 class TestOptimizers:
     def test_adam_state_threads_through_round(self):
         pcfg = ProtocolConfig(n_devices=2, n_d=1, n_g=1, sample_size=4,
