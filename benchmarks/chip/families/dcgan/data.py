@@ -1,4 +1,4 @@
-"""The benchmark's own image traffic, made on the device from the seed.
+"""The DCGAN family's image traffic, made on the device from the seed.
 
 A copy of the synthetic generator the repository uses for its datasets:
 each dataset is a mixture of `n_modes` smooth patterns (a sum of four

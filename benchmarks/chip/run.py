@@ -4,10 +4,12 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|1>
 
-Set-up makes the cell's image shards on the device and the weights in
-one jitted call, both from --seed, builds the program's fused Trainer,
-and drives it through the first three dispatches (the first compiles,
-or loads from the compile cache in `.jax_cache/` at the checkout root).
+Set-up makes the cell's data shards on the device and the weights in
+one jitted call, both from --seed and by the configuration's model
+family (`families/<family>/reference.py`), builds the program's fused
+Trainer, and drives it through the first three dispatches (the first
+compiles, or loads from the compile cache in `.jax_cache/` at the
+checkout root).
 The window then calls `Trainer.run(rounds_per_dispatch)` and waits for
 the state, in whole dispatches, until --seconds have passed. After the
 window the program is freed and the plain f32 reference
@@ -63,7 +65,10 @@ def _window(trainer, rounds_per_dispatch: int, seconds: float):
 
 
 def _breakdown(devices, host):
-    from benchmarks.chip import tracereduce
+    """The device ops that took most time, and the longest idle gaps of
+    the idlest chip, each named by the program span at its middle, else
+    by the innermost host event there."""
+    from benchmarks.chip import stagetrace, tracereduce
     ops = {}
     for d in devices:
         for name, ns in d.op_ns.items():
@@ -71,28 +76,40 @@ def _breakdown(devices, host):
     idlest = max(devices, key=lambda d: 1.0 - d.busy_s / d.window_s)
     return {"device_ops": sorted(([n, s] for n, s in ops.items()),
                                  key=lambda x: -x[1])[:10],
-            "idle_gaps": [list(g) for g in
-                          tracereduce.host_activity(host, idlest.gaps)]}
+            "idle_gaps": [list(g) for g in tracereduce.host_activity(
+                host, idlest.gaps, prefer=stagetrace.PROGRAM_SPANS)]}
+
+
+def reader_ctx(cell, devices, rounds: int, window_s: float, traced, host):
+    """What a per-layer reader (`layer_metrics/<metric>.py`) reads of a
+    traced window: `traced` are its `tracereduce.Device`s, `host` its
+    host events (start_ns, dur_ns, name)."""
+    from benchmarks.chip import spec
+    fl = cell.flops_module()
+    return types.SimpleNamespace(
+        cell=cell, chips=len(devices), rounds=rounds, window_s=window_s,
+        devices=traced, host=host, peaks=spec.peaks(devices[0].device_kind),
+        flops=fl.round_flops(cell.config, cell.traffic, len(devices)),
+        wavg_bytes=fl.wavg_bytes(cell.config, cell.traffic))
 
 
 def prepare(cell, seed: int, devices):
-    """The cell's inputs from the seed: image shards on the device (one
-    worker per chip on a mesh), the weights' jitted maker and a host copy
-    of them, and the trainer's key."""
+    """The cell's inputs from the seed, by the model family: data shards
+    on the device (one worker per chip on a mesh), the weights' jitted
+    maker and a host copy of them, and the trainer's key."""
     import jax
 
-    from benchmarks.chip import data, reference, sut
+    from benchmarks.chip import reference, sut
 
     cfg, tr = cell.config, cell.traffic
+    family = reference.family(cfg)
     key = seed_key(seed)
     k_data, k_weights, k_train = (jax.random.fold_in(key, i)
                                   for i in (1, 2, 3))
     mesh = sut.make_mesh(devices) if tr["layout"] == "mesh" else None
     with jax.default_device(devices[0]):
-        shards = data.make_shards(k_data, tr["workers"],
-                                  cfg["train_images"] // tr["workers"],
-                                  cfg, mesh)
-        init = jax.jit(lambda k: reference.init_params(k, cfg))
+        shards = family.make_shards(k_data, tr["workers"], cfg, mesh)
+        init = jax.jit(lambda k: family.init_params(k, cfg))
         params0 = jax.device_get(init(k_weights))
     return types.SimpleNamespace(devices=devices, mesh=mesh, shards=shards,
                                  params0=params0, key=k_train,
@@ -178,12 +195,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     result = {"correct": bool(ok and rounds > 0 and failed == 0),
               "attempted": int(rounds), "failed": failed}
     if trace:
-        fl = cell.flops_module()
-        ctx = types.SimpleNamespace(
-            cell=cell, chips=len(devices), rounds=rounds, window_s=window_s,
-            devices=traced, peaks=spec.peaks(devices[0].device_kind),
-            flops=fl.round_flops(cell.config, cell.traffic, len(devices)),
-            wavg_bytes=fl.wavg_bytes(cell.config, cell.traffic))
+        ctx = reader_ctx(cell, devices, rounds, window_s, traced, host)
         metrics = {}
         for m in cell.per_layer:
             value = spec.reader(m["name"])(ctx)

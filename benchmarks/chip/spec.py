@@ -4,15 +4,21 @@
 configurations. Each configuration is `configs/<config>.json`, each
 traffic mix `traffic/<traffic>.json`, each cell's correctness limits
 `limits/<cell>.json`, each per-layer metric a reader
-`layer_metrics/<metric>.py`, each model family's operation count
-`flops/<family>.py`, and the chips' peaks `peaks.json`. A cell, a
-configuration or a metric is added by adding files and entries only.
+`layer_metrics/<metric>.py`, and the chips' peaks `peaks.json`. The
+configuration's `family` names the model: its data, weights and plain
+networks `families/<family>/reference.py`, the program's model
+`families/<family>/program.py` and its operation count
+`flops/<family>.py` (see `families/__init__.py`). A cell, a
+configuration, a family or a metric is added by adding files and
+entries only.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -48,8 +54,41 @@ class Cell:
     per_layer: tuple      # ... and with --trace 1
 
     def flops_module(self):
-        return importlib.import_module(
-            f"benchmarks.chip.flops.{self.config['family']}")
+        return family_module(self.config["family"], "flops")
+
+
+def _family_modules(family: str) -> dict:
+    return {"reference": f"benchmarks.chip.families.{family}.reference",
+            "program": f"benchmarks.chip.families.{family}.program",
+            "flops": f"benchmarks.chip.flops.{family}"}
+
+
+def family_module(family: str, part: str):
+    """The `part` ("reference", "program" or "flops") of model family
+    `family`."""
+    return importlib.import_module(_family_modules(family)[part])
+
+
+def _found(module: str) -> bool:
+    if module in sys.modules:
+        return True
+    try:
+        return importlib.util.find_spec(module) is not None
+    except ModuleNotFoundError:
+        return False
+
+
+def _check_family(config: str, family: str):
+    """Raise unless every part of `family` is there; the program's part
+    is looked for, not imported."""
+    wanted = _family_modules(family).values()
+    missing = [m for m in wanted if not _found(m)]
+    if missing:
+        raise KeyError(
+            f"configuration {config!r}: model family {family!r} needs "
+            + ", ".join(m.replace(".", "/") + ".py" for m in wanted)
+            + "; not found: "
+            + ", ".join(m.replace(".", "/") + ".py" for m in missing))
 
 
 def reader(metric: str):
@@ -70,9 +109,10 @@ def cell(name: str, bench: dict | None = None) -> Cell:
                        f"{[w['name'] for w in bench['workloads']]})")
     cfg_entry = next(c for c in bench["configs"]
                      if c["name"] == entry["config"])
+    config = _load(ROOT / cfg_entry["file"])
+    _check_family(cfg_entry["name"], config.get("family"))
     return Cell(
-        name=name, chips=entry["chips"],
-        config=_load(ROOT / cfg_entry["file"]),
+        name=name, chips=entry["chips"], config=config,
         traffic=_load(HERE / "traffic" / f"{entry['traffic']}.json"),
         limits=_load(HERE / "limits" / f"{name}.json"),
         end_to_end=tuple(m for m in bench["end_to_end"]

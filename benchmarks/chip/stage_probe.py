@@ -11,11 +11,9 @@ window of run.TRACE_SECONDS, both in whole dispatches. The last line of
 standard output is one JSON object:
 
 - rounds_per_s of each window, so the cost of tracing when it is on;
-- the cell's per-layer metrics (`BENCHMARK.json`) and the stage readers
-  (`layer_metrics/{a1_local,uplink,a2_average,a3_server}_share.py`,
-  `layer_metrics/host_overhead_ms.py`), all read from the traced window,
-  the devices carrying `stagetrace.scope_ns` and the context the host
-  events;
+- the cell's per-layer metrics (`BENCHMARK.json`), the stage shares
+  and `host_overhead_ms` among them, read from the traced window as
+  run.py reads them;
 - the device time a round by stage, summed over chips, and the longest
   unscoped ops;
 - every idle gap of 1 ms or more on each chip: its length, the program
@@ -34,20 +32,18 @@ import json
 import sys
 import tempfile
 import time
-import types
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-NEW_METRICS = ("a1_local_share", "uplink_share", "a2_average_share",
-               "a3_server_share", "host_overhead_ms")
 RECORD_BEFORE_NS, RECORD_AFTER_NS = 3e6, 16e6
 
 
-def _record(path, device, ops, modules, host, source):
+def _record(path, device, host, source):
     """The window around `device`'s longest idle gap, times from its
     start; programs clipped to it, ops and host events whole."""
+    ops, modules = device.ops, device.modules
     start, length = max(device.gaps, key=lambda g: g[1])
     lo, hi = start - RECORD_BEFORE_NS, start + length + RECORD_AFTER_NS
     inside = lambda s, d: s < hi and s + d > lo
@@ -83,19 +79,10 @@ def probe(cell, seed: int, seconds: float, devices, record=None) -> dict:
             rounds, window_s = run._window(trainer, r, run.TRACE_SECONDS)
             jax.profiler.stop_trace()
             traced, host = tracereduce.read_trace(trace_dir)
-            planes = stagetrace.read_planes(trace_dir)
-    ops = {name: plane_ops for name, (_, plane_ops) in planes.items()}
-    for d in traced:
-        d.scope_ns = stagetrace.scope_ns(ops[d.name])
 
-    fl = cell.flops_module()
-    ctx = types.SimpleNamespace(
-        cell=cell, chips=len(devices), rounds=rounds, window_s=window_s,
-        devices=traced, peaks=spec.peaks(devices[0].device_kind),
-        flops=fl.round_flops(cell.config, cell.traffic, len(devices)),
-        wavg_bytes=fl.wavg_bytes(cell.config, cell.traffic), host=host)
-    names = [m["name"] for m in cell.per_layer] + list(NEW_METRICS)
-    metrics = {n: spec.reader(n)(ctx) for n in names}
+    ctx = run.reader_ctx(cell, devices, rounds, window_s, traced, host)
+    metrics = {m["name"]: spec.reader(m["name"])(ctx)
+               for m in cell.per_layer}
 
     by_stage = {}
     for d in traced:
@@ -103,15 +90,14 @@ def probe(cell, seed: int, seconds: float, devices, record=None) -> dict:
             by_stage[k] = by_stage.get(k, 0.0) + ns * 1e-6 / rounds
     unscoped = {}
     for d in traced:
-        for o in ops[d.name]:
+        for o in d.ops:
             if (o.category not in tracereduce.CONTAINERS
                     and stagetrace.stage_of(o.op_name)
                     == stagetrace.UNSCOPED):
                 key = tracereduce.short_name(o)
                 unscoped[key] = unscoped.get(key, 0.0) + o.dur_ns * 1e-6
     if record and traced:
-        modules, first_ops = planes[traced[0].name]
-        _record(record, traced[0], first_ops, modules, host,
+        _record(record, traced[0], host,
                 f"{devices[0].device_kind}, {cell.name}, seed {seed}: a "
                 f"window around the longest idle gap of the first chip")
     return {

@@ -13,6 +13,10 @@ land in the trace the benchmark already reads, on one clock:
 - scope_ns: device time of the leaf ops (loops left out, as
   `tracereduce.reduce_device` leaves them out) by stage, so the stages
   and `unscoped` sum to all leaf-op time;
+- named_ns: the same time by every named scope in the stack, at any
+  depth, wrappers taken off, so an op under `round.a1_local/ssm.scan`
+  counts for both: the scopes a model of any family puts on its own
+  layers, for readers of their own (`named_share`);
 - host overhead: the length of each `trainer.dispatch` span less its
   `trainer.wait` children, the part of a dispatch in which the host and
   not the device is the one working.
@@ -23,7 +27,6 @@ nothing of the program.
 from __future__ import annotations
 
 import collections
-import glob
 import re
 
 from benchmarks.chip import tracereduce
@@ -35,6 +38,7 @@ DISPATCH = "trainer.dispatch"
 WAIT = "trainer.wait"
 PROGRAM_SPANS = ("trainer.", "shard_round.")
 _STAGE = re.compile(r"(?:^|[/(])(round\.[A-Za-z0-9_]+)")
+_WRAPPER = re.compile(r"[A-Za-z_]\w*\((.*)\)")
 
 
 def stage_of(tf_op: str) -> str:
@@ -50,6 +54,33 @@ def scope_ns(ops) -> dict:
     for o in ops:
         if o.category not in tracereduce.CONTAINERS:
             out[stage_of(o.op_name)] += o.dur_ns
+    return dict(out)
+
+
+def scopes_of(tf_op: str) -> set:
+    """Every named component of an op's name stack but the op's own
+    (the last), each wrapper such as `vmap(...)` or `jit(...)` taken off
+    (`vmap(transpose(jvp(round.a1_local)))` -> `round.a1_local`). The
+    structural ones (`while`, `body`, ...) are among them; no reader asks
+    for those."""
+    out = set()
+    for part in tf_op.split("/")[:-1]:
+        while (m := _WRAPPER.fullmatch(part)) is not None:
+            part = m.group(1)
+        if part:
+            out.add(part)
+    return out
+
+
+def named_ns(ops) -> dict:
+    """Device time of the leaf ops by every named scope they run under."""
+    out, stacks = collections.Counter(), {}
+    for o in ops:
+        if o.category not in tracereduce.CONTAINERS:
+            if o.op_name not in stacks:
+                stacks[o.op_name] = scopes_of(o.op_name)
+            for scope in stacks[o.op_name]:
+                out[scope] += o.dur_ns
     return dict(out)
 
 
@@ -107,42 +138,25 @@ def gaps(host_events, device_gaps, min_ns: float = 1e6):
             for s, n in sorted(device_gaps) if n >= min_ns]
 
 
+def _leaf_ns(ctx) -> float:
+    return sum(sum(d.scope_ns.values()) for d in ctx.devices)
+
+
 def share(ctx, stage: str):
     """`stage`'s share of all leaf-op time on all chips, in %; None where
-    the devices carry no `scope_ns`."""
-    per_chip = [getattr(d, "scope_ns", None) for d in ctx.devices]
-    if not per_chip or any(s is None for s in per_chip):
+    no op on any chip runs under a stage (a program without the
+    scopes)."""
+    total = _leaf_ns(ctx)
+    if total <= 0 or all(set(d.scope_ns) <= {UNSCOPED}
+                         for d in ctx.devices):
         return None
-    total = sum(sum(s.values()) for s in per_chip)
-    if total <= 0:
-        return None
-    return 100.0 * sum(s.get(stage, 0.0) for s in per_chip) / total
+    return 100.0 * sum(d.scope_ns.get(stage, 0.0)
+                       for d in ctx.devices) / total
 
 
-def read_planes(trace_dir: str) -> dict:
-    """{device plane name: ((start_ns, dur_ns) of its programs, its
-    `tracereduce.Op`s, each with its `tf_op`)} of the one `*.xplane.pb`
-    under trace_dir."""
-    import jax
-    from benchmarks.chip import xplane
-
-    files = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    if len(files) != 1:
-        raise ValueError(f"expected one trace under {trace_dir}, found "
-                         f"{len(files)}")
-    meta = xplane.event_metadata(files[0])
-    out = {}
-    for plane in jax.profiler.ProfileData.from_file(files[0]).planes:
-        if not plane.name.startswith("/device:TPU:"):
-            continue
-        stats = meta.get(plane.name, {})
-        events = {line.name: line.events for line in plane.lines}
-        out[plane.name] = (
-            [(e.start_ns, e.duration_ns)
-             for e in events.get("XLA Modules", ())],
-            [tracereduce.Op(e.start_ns, e.duration_ns, e.name,
-                            str(stats.get(e.name, {}).get("hlo_category",
-                                                          "")),
-                            str(stats.get(e.name, {}).get("tf_op", "")))
-             for e in events.get("XLA Ops", ())])
-    return out
+def named_share(ctx, scope: str):
+    """The share of all leaf-op time on all chips that runs under the
+    named scope `scope` at any depth, in %; None where no op does."""
+    total = _leaf_ns(ctx)
+    under = sum(d.named_ns.get(scope, 0.0) for d in ctx.devices)
+    return 100.0 * under / total if total > 0 and under > 0 else None

@@ -1,16 +1,16 @@
 """The system under test: the repository's distributed-GAN trainer,
 `repro.core.engine.Trainer` with the fused driver, driven as a user
-drives it. This is the only module of the benchmark that imports the
-program."""
+drives it, on the model that the configuration's family names
+(`families/<family>/program.py`). Those and this are the only modules
+of the benchmark that import the program."""
 from __future__ import annotations
 
 import jax
 import numpy as np
 
+from benchmarks.chip import spec
 from repro.configs.base import ProtocolConfig
-from repro.configs.dcgan import DCGANConfig
 from repro.core import Trainer
-from repro.models.specs import make_dcgan_spec
 
 
 def make_mesh(devices):
@@ -21,7 +21,7 @@ def make_mesh(devices):
 
 
 def trainer(cfg: dict, traffic: dict, init_params, shards, key, mesh=None):
-    """A fused Trainer over pre-sharded (K, n_k, H, W, C) `shards`, its
+    """A fused Trainer over pre-sharded (K, n_k, ...) `shards`, its
     weights from the zero-argument `init_params`."""
     pcfg = ProtocolConfig(
         n_devices=traffic["workers"], n_d=traffic["n_d"], n_g=traffic["n_g"],
@@ -31,12 +31,11 @@ def trainer(cfg: dict, traffic: dict, init_params, shards, key, mesh=None):
         scheduling_ratio=traffic["scheduling_ratio"],
         quantize_bits=traffic["quantize_bits"],
         optimizer=traffic["optimizer"])
-    dcfg = DCGANConfig(nz=cfg["nz"], ngf=cfg["ngf"], ndf=cfg["ndf"],
-                       nc=cfg["nc"], image_size=cfg["image_size"])
+    model = spec.family_module(cfg["family"], "program").spec(cfg)
     layout = {"layout": traffic["layout"]}
     if traffic["layout"] == "mesh":
         layout.update(mesh=mesh, avg_impl=traffic["avg_impl"])
-    return Trainer(make_dcgan_spec(dcfg), pcfg, lambda _key: init_params(),
+    return Trainer(model, pcfg, lambda _key: init_params(),
                    shards, key, algorithm=traffic["algorithm"],
                    driver="fused", partition=None, **layout)
 

@@ -19,7 +19,10 @@ after the program is refactored.
   all-reduce, reduce-scatter, all-to-all, collective-permute);
 - kernels: custom calls (Pallas kernels among them) by their HLO name
   without its numeric suffix, which the program's jitted kernel wrapper
-  gives them (`wavg_pallas.9` -> `wavg_pallas`).
+  gives them (`wavg_pallas.9` -> `wavg_pallas`);
+- by name: each op keeps the JAX name stack it came from (its `tf_op`),
+  and leaf-op time is summed by the round's stage and by every named
+  scope in the stack (`stagetrace.scope_ns`, `stagetrace.named_ns`).
 """
 from __future__ import annotations
 
@@ -93,6 +96,10 @@ class Device:
     kernel_calls: dict     # kernel name -> number of calls
     op_ns: dict            # short op name -> total
     gaps: list             # (start, length) of idle gaps inside the window
+    modules: list          # (start, length) of each program run
+    ops: list              # every `Op`, loops too, with its `tf_op`
+    scope_ns: dict         # stage -> leaf-op time (`stagetrace.scope_ns`)
+    named_ns: dict         # named scope -> leaf-op time (`.named_ns`)
 
     @property
     def window_s(self) -> float:
@@ -105,6 +112,8 @@ class Device:
 
 def reduce_device(name: str, modules, ops) -> Device:
     """modules: (start_ns, dur_ns) of the programs; ops: `Op`s."""
+    from benchmarks.chip import stagetrace   # which imports this module
+
     if not modules:
         raise ValueError(f"{name}: no program ran in the traced window")
     lo = min(s for s, _ in modules)
@@ -134,18 +143,22 @@ def reduce_device(name: str, modules, ops) -> Device:
             kernel_ns[k] += o.dur_ns
             kernel_calls[k] += 1
     return Device(name, (lo, hi), busy, mxu, coll, dict(kernel_ns),
-                  dict(kernel_calls), dict(op_ns), gaps)
+                  dict(kernel_calls), dict(op_ns), gaps, list(modules),
+                  list(ops), stagetrace.scope_ns(ops),
+                  stagetrace.named_ns(ops))
 
 
-def host_activity(host_events, gaps, top: int = 10):
+def host_activity(host_events, gaps, top: int = 10, prefer=()):
     """The `top` longest idle gaps, each named by the innermost host
-    event that covers its middle. host_events: (start_ns, dur_ns, name)."""
+    event that covers its middle, among those whose names start with one
+    of `prefer` where one does. host_events: (start_ns, dur_ns, name)."""
     named = []
     for start, length in sorted(gaps, key=lambda g: -g[1])[:top]:
         mid = start + length / 2
         covering = [(d, n) for s, d, n in host_events if s <= mid <= s + d]
-        named.append((min(covering)[1] if covering else "no host event",
-                      length * 1e-9))
+        preferred = [(d, n) for d, n in covering if n.startswith(prefer)]
+        named.append((min(preferred or covering,
+                          default=(0, "no host event"))[1], length * 1e-9))
     return named
 
 

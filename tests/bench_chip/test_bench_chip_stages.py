@@ -101,9 +101,9 @@ def test_shares_are_of_all_leaf_op_time_on_all_chips():
 
 
 def test_readers_find_nothing_without_scopes_or_spans():
-    """Devices without `scope_ns` and a context without host events, as
-    a program without stage scopes and dispatch spans gives: no value,
-    no error."""
+    """Devices on which no op runs under a stage and a context without
+    host events, as a program without stage scopes and dispatch spans
+    gives: no value, no error."""
     ctx = _ctx([_device(), _device({"unscoped": 5})], host=None)
     assert {m: spec.reader(m)(ctx) for m in READERS} == dict.fromkeys(
         READERS)
@@ -146,15 +146,17 @@ def _plane(pid, name, lines, metadata, stat_names=()):
     return _field(1, out)
 
 
-def test_read_planes_reads_programs_and_tf_ops(tmp_path):
-    """A hand-made trace: a TPU plane with one program and two ops, a
-    host plane with one dispatch span."""
-    conv = "%fusion.1 = f32[8] fusion(x)"
+CONV = "%fusion.1 = f32[8] fusion(x)"
+
+
+def _hand_made_trace(tmp_path):
+    """A TPU plane with one program and two ops, a host plane with one
+    dispatch span; the directory that holds it."""
     tpu = _plane(1, "/device:TPU:0",
                  [("XLA Modules", [(1, 0, 100)]),
                   ("XLA Ops", [(2, 10, 30), (3, 50, 20)])],
                  {1: ("jit_run_chunk", {}),
-                  2: (conv, {1: "convolution fusion",
+                  2: (CONV, {1: "convolution fusion",
                              2: "jit(f)/vmap(round.a1_local)/conv"}),
                   3: ("%fusion.2 = f32[8] fusion(y)",
                       {1: "loop fusion", 2: "jit(f)/add"})},
@@ -162,16 +164,75 @@ def test_read_planes_reads_programs_and_tf_ops(tmp_path):
     host = _plane(2, "/host:CPU", [("python", [(1, 0, 200)])],
                   {1: ("trainer.dispatch", {})})
     (tmp_path / "t.xplane.pb").write_bytes(tpu + host)
-    modules, ops = stagetrace.read_planes(str(tmp_path))["/device:TPU:0"]
-    assert modules == [(1000, 100)]
-    assert [(o.start_ns, o.dur_ns, o.name, o.category) for o in ops] == [
-        (1010, 30, conv, "convolution fusion"),
-        (1050, 20, "%fusion.2 = f32[8] fusion(y)", "loop fusion")]
-    assert stagetrace.scope_ns(ops) == {"round.a1_local": 30,
-                                        "unscoped": 20}
-    (dev,), host_events = tracereduce.read_trace(str(tmp_path))
+    return str(tmp_path)
+
+
+def test_read_planes_reads_programs_and_tf_ops(tmp_path):
+    """read_trace keeps each device's programs and ops, each op with its
+    `tf_op`, and buckets the leaf ops by stage."""
+    (dev,), host_events = tracereduce.read_trace(_hand_made_trace(tmp_path))
+    assert dev.modules == [(1000, 100)]
+    assert [(o.start_ns, o.dur_ns, o.name, o.category, o.op_name)
+            for o in dev.ops] == [
+        (1010, 30, CONV, "convolution fusion",
+         "jit(f)/vmap(round.a1_local)/conv"),
+        (1050, 20, "%fusion.2 = f32[8] fusion(y)", "loop fusion",
+         "jit(f)/add")]
+    assert dev.scope_ns == {"round.a1_local": 30, "unscoped": 20}
     assert dev.busy_ns == 50 and dev.mxu_ns == 30
     assert host_events == [(1000, 200, "trainer.dispatch")]
+
+
+def test_read_trace_puts_the_scopes_of_its_ops_on_each_device(tmp_path):
+    """A device read through read_trace carries the `scope_ns` and
+    `named_ns` that stagetrace gives for its own ops."""
+    (dev,), _ = tracereduce.read_trace(_hand_made_trace(tmp_path))
+    assert dev.scope_ns == stagetrace.scope_ns(dev.ops)
+    assert dev.named_ns == stagetrace.named_ns(dev.ops)
+    assert dev.named_ns == {"f": 50, "round.a1_local": 30}
+
+
+@pytest.mark.parametrize("tf_op,scopes", [
+    ("jit(run_chunk)/while/body/closed_call/vmap(round.a1_local)/while/"
+     "body/closed_call/transpose(jvp())/conv_general_dilated:",
+     {"run_chunk", "while", "body", "closed_call", "round.a1_local"}),
+    ("vmap(transpose(jvp(round.a1_local)))/ssm.scan/jvp(moe.dispatch)/dot",
+     {"round.a1_local", "ssm.scan", "moe.dispatch"}),
+    ("jit(f)/transpose(jvp(round.a3_server))/round.a3_server/mul",
+     {"f", "round.a3_server"}),
+    ("round.uplink", set()),
+    ("", set()),
+], ids=["vmapped", "nested", "repeated", "op-only", "empty"])
+def test_scopes_of_unwraps_every_component_but_the_op(tf_op, scopes):
+    assert stagetrace.scopes_of(tf_op) == scopes
+
+
+def test_named_ns_counts_an_op_under_every_scope_once():
+    """Nested and transform-wrapped scopes: an op counts for each scope
+    in its stack, once, and loops are left out."""
+    ops = [Op(0, 100, "%while.1", "while", "jit(f)/round.a1_local/while"),
+           Op(5, 10, "%fusion.2", "loop fusion",
+              "jit(f)/vmap(round.a1_local)/ssm.scan/transpose(jvp("
+              "ssm.scan))/mul"),
+           Op(20, 4, "%fusion.3", "convolution fusion",
+              "jit(f)/round.a1_local/vmap(moe.dispatch)/dot_general"),
+           Op(30, 6, "%copy.4", "data formatting", "jit(f)/copy"),
+           Op(40, 2, "%fusion.5", "loop fusion", "")]
+    assert stagetrace.named_ns(ops) == {"f": 20, "round.a1_local": 14,
+                                        "ssm.scan": 10, "moe.dispatch": 4}
+    assert stagetrace.scope_ns(ops) == {"round.a1_local": 14,
+                                        "unscoped": 8}
+
+
+def test_named_share_is_of_all_leaf_op_time_and_none_without_the_scope():
+    chips = [_device(), _device()]
+    chips[0].named_ns = {"ssm.scan": 5, "round.a1_local": 8}
+    chips[1].named_ns = {"ssm.scan": 3}
+    ctx = _ctx(chips)
+    assert stagetrace.named_share(ctx, "ssm.scan") == pytest.approx(40.0)
+    assert stagetrace.named_share(ctx, "round.a1_local") == \
+        pytest.approx(40.0)
+    assert stagetrace.named_share(ctx, "moe.dispatch") is None
 
 
 def _recorded(name):
@@ -202,7 +263,7 @@ def test_probe_records_the_window_around_the_longest_gap(tmp_path):
             (1.5e6, 1e3, "PjitFunction(run_chunk)"),
             (5e6, 6e6, "trainer.dispatch"), (30e6, 1e6, "trainer.dispatch")]
     path = tmp_path / "window.json"
-    stage_probe._record(path, dev, ops, modules, host, "made up")
+    stage_probe._record(path, dev, host, "made up")
     data = json.loads(path.read_text())
     lo = 1e6 - stage_probe.RECORD_BEFORE_NS
     assert data["source"] == "made up"
@@ -219,7 +280,6 @@ def scoped_window():
     the next."""
     data, ops, host = _recorded("trace_v5e_dcgan32_scopes.json")
     dev = tracereduce.reduce_device("/device:TPU:0", data["modules"], ops)
-    dev.scope_ns = stagetrace.scope_ns(ops)
     return _ctx([dev], rounds=20, host=host)
 
 
@@ -247,3 +307,33 @@ def test_recorded_idle_gaps_fall_in_host_work_of_a_dispatch(scoped_window):
                         "trainer.records", "shard_round.signature",
                         "shard_round.place"), span
         assert host_share > 0.5, (length, span, host_share)
+
+
+def test_a_named_stage_holds_at_least_its_deepest_ops(scoped_window):
+    """By every scope in the stack, a stage holds its own ops and those
+    of any stage nested inside it."""
+    for stage in stagetrace.STAGES:
+        assert stagetrace.named_share(scoped_window, stage) >= \
+            stagetrace.share(scoped_window, stage) - 1e-9, stage
+
+
+def test_breakdown_names_idle_gaps_after_program_spans(scoped_window):
+    """The longest gap lies in a dispatch's read-back: the breakdown
+    names it so, where the innermost host event is a numpy call; a gap
+    that no program span covers keeps its host event's name."""
+    from benchmarks.chip import run
+    (dev,) = scoped_window.devices
+    gaps = run._breakdown([dev], scoped_window.host)["idle_gaps"]
+    plain = tracereduce.host_activity(scoped_window.host, dev.gaps)
+    assert gaps[0] == ["trainer.readback", plain[0][1]]
+    assert plain[0][0] != "trainer.readback"
+    outside = tracereduce.host_activity(
+        [(0, 10, "PjitFunction(f)"), (0, 100, "trainer.dispatch")],
+        [(20, 4), (2, 6)], prefer=stagetrace.PROGRAM_SPANS)
+    assert outside == [("trainer.dispatch", pytest.approx(6e-9)),
+                       ("trainer.dispatch", pytest.approx(4e-9))]
+    bare = tracereduce.host_activity([(0, 10, "PjitFunction(f)")],
+                                     [(2, 6), (50, 4)],
+                                     prefer=stagetrace.PROGRAM_SPANS)
+    assert bare == [("PjitFunction(f)", pytest.approx(6e-9)),
+                    ("no host event", pytest.approx(4e-9))]

@@ -2,6 +2,7 @@
 files, its operation counts, its trace reduction and its refusals."""
 from __future__ import annotations
 
+import ast
 import json
 import os
 import subprocess
@@ -54,6 +55,43 @@ def test_benchmark_names_the_files_it_uses():
         assert (ROOT / "benchmarks/chip/layer_metrics" /
                 f"{m['name']}.py").is_file()
         assert set(m.get("workloads", CELLS)) <= set(CELLS)
+
+
+def _imports(path: Path) -> set:
+    tree = ast.parse(path.read_text())
+    names = {a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+             for a in n.names}
+    return names | {n.module for n in ast.walk(tree)
+                    if isinstance(n, ast.ImportFrom) and n.module}
+
+
+def test_only_the_system_under_test_imports_the_program():
+    """The yardstick reads the program only through `sut.py` and each
+    family's `program.py`."""
+    chip = ROOT / "benchmarks/chip"
+    allowed = {chip / "sut.py", *chip.glob("families/*/program.py")}
+    assert len(allowed) >= 2
+    for path in sorted(chip.rglob("*.py")):
+        program = {m for m in _imports(path)
+                   if m == "repro" or m.startswith("repro.")}
+        if path in allowed:
+            assert program, path
+        else:
+            assert not program, (path, program)
+
+
+def test_an_unknown_family_fails_at_cell_naming_the_files(tmp_path):
+    cfg = tmp_path / "c.json"
+    cfg.write_text(json.dumps({"name": "c", "family": "nosuch"}))
+    bench = {**BENCH, "configs": [{"name": "c", "file": str(cfg)}],
+             "workloads": [{"name": CELLS[0], "config": "c",
+                            "traffic": "k10-stacked", "chips": 1}]}
+    with pytest.raises(KeyError) as err:
+        spec.cell(CELLS[0], bench)
+    for f in ("benchmarks/chip/families/nosuch/reference.py",
+              "benchmarks/chip/families/nosuch/program.py",
+              "benchmarks/chip/flops/nosuch.py"):
+        assert f in str(err.value)
 
 
 def test_peaks_lookup_refuses_an_unknown_device_kind():
