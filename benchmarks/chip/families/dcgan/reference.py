@@ -26,11 +26,16 @@ from benchmarks.chip.reference import HI, mxu
 MODEL_KEYS = ("nz", "ngf", "ndf", "nc", "image_size")
 
 
+def samples(cfg: dict) -> int:
+    """The job's number of training images."""
+    return cfg["train_images"]
+
+
 def make_shards(key, workers: int, cfg: dict, mesh=None):
-    """(workers, train_images // workers, H, W, C) image shards on the
-    device (`data.make_shards`)."""
-    return data.make_shards(key, workers, cfg["train_images"] // workers,
-                            cfg, mesh)
+    """(workers, samples // workers, H, W, C) image shards on the device
+    (`data.make_shards`)."""
+    return data.make_shards(key, workers, samples(cfg) // workers, cfg,
+                            mesh)
 
 
 def _stages(image_size: int) -> int:

@@ -25,6 +25,7 @@ program's place for the calibration of the limits:
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +135,69 @@ def _shared_noise(round_key, j, n, cfg):
         n, cfg)
 
 
+def shared_fakes(gen, round_key, cfg, tr, variant=None):
+    """(n_d, m_k, ...) fakes of the shared noise, the same for every
+    worker."""
+    return jax.lax.map(
+        lambda j: family(cfg).generator(
+            gen, _shared_noise(round_key, j, tr["m_k"], cfg), cfg, variant),
+        jnp.arange(tr["n_d"]))
+
+
+def local_round(disc, fakes, real_k, k, round_key, cfg, tr, variant=None):
+    """Algorithm 1 on worker k from the global discriminator, and what
+    the server receives of it: (upload, the last local objective)."""
+    used = tr["m_k"] // 2 if variant == "half_batch" else tr["m_k"]
+
+    def step(d, inp):
+        x, fake = inp
+        obj, grad = jax.value_and_grad(disc_objective)(
+            d, x[:used], fake[:used], cfg, variant)
+        return jax.tree.map(lambda p, g: p + tr["lr_d"] * g, d,
+                            grad), obj                   # ascent on eq. (2)
+
+    d, objs = jax.lax.scan(step, disc, (real_k, fakes))
+    qkey = jax.random.fold_in(jax.random.fold_in(round_key, SALT_QUANT), k)
+    return quantize_upload(qkey, d, tr["quantize_bits"]), objs[-1]
+
+
+def average_weights(tr):
+    """(K,) weights of Algorithm 2, m_k / sum of m_k."""
+    k_workers, m = tr["workers"], float(tr["m_k"])
+    return jnp.full((k_workers,), m) / (k_workers * m)
+
+
+def server_round(gen, disc, round_key, cfg, tr, variant=None):
+    """Algorithm 3 against the global discriminator: (gen, the loss at
+    the last step)."""
+    def step(g, j):
+        z = _shared_noise(round_key, j, tr["M"], cfg)
+        loss, grad = jax.value_and_grad(gen_objective)(g, disc, z, cfg,
+                                                       variant)
+        return jax.tree.map(lambda p, q: p - tr["lr_g"] * q, g, grad), loss
+
+    gen, losses = jax.lax.scan(step, gen, jnp.arange(tr["n_g"]))
+    return gen, losses[-1]
+
+
+class Static:
+    """A JSON value as a static argument of `jax.jit`: hashed and compared
+    by its canonical JSON text, and handed on as it was loaded, so that
+    a model key may hold a list or a dict."""
+
+    __slots__ = ("value", "_text")
+
+    def __init__(self, value):
+        self.value = value
+        self._text = json.dumps(value, sort_keys=True)
+
+    def __hash__(self):
+        return hash(self._text)
+
+    def __eq__(self, other):
+        return isinstance(other, Static) and self._text == other._text
+
+
 @functools.partial(jax.jit, static_argnames=("cfg_items", "traffic_items",
                                              "variant"))
 def reference_round(params, real, round_key, *, cfg_items, traffic_items,
@@ -141,54 +205,45 @@ def reference_round(params, real, round_key, *, cfg_items, traffic_items,
     """One serial round with every worker scheduled.
 
     real: (K, n_d, m_k, ...), worker k's real batches of its local
-    steps. Returns (params, (disc_objective, gen_objective)) with the
+    steps; cfg_items: a `Static` of the family and its model keys.
+    Returns (params, (disc_objective, gen_objective)) with the
     objectives as the program reports them: the workers' mean local
-    objective at their last step, and the server's loss at its last."""
-    cfg, tr = dict(cfg_items), dict(traffic_items)
-    k_workers, n_d, n_g = tr["workers"], tr["n_d"], tr["n_g"]
-    m, big_m = tr["m_k"], tr["M"]
+    objective at their last step, and the server's loss at its last.
+
+    Algorithm 2 is a running sum over the workers, so that one upload is
+    live at a time and memory does not grow with K."""
+    cfg, tr = cfg_items.value, dict(traffic_items)
     gen, disc = params["gen"], params["disc"]
-    fakes = jax.lax.map(                                 # same for all k
-        lambda j: family(cfg).generator(
-            gen, _shared_noise(round_key, j, m, cfg), cfg, variant),
-        jnp.arange(n_d))
-    used = m // 2 if variant == "half_batch" else m
+    fakes = shared_fakes(gen, round_key, cfg, tr, variant)
 
-    def worker(args):
-        k, real_k = args
+    def worker(total, args):
+        k, real_k, w = args
+        upload, obj = local_round(disc, fakes, real_k, k, round_key, cfg, tr,
+                                  variant)
+        if variant == "no_exchange":      # the server keeps worker 0's
+            total = jax.tree.map(lambda t, u: jnp.where(k == 0, u, t),
+                                 total, upload)
+        else:
+            total = jax.tree.map(lambda t, u: t + w * u, total, upload)
+        return total, obj
 
-        def step(d, inp):
-            x, fake = inp
-            obj, grad = jax.value_and_grad(disc_objective)(
-                d, x[:used], fake[:used], cfg, variant)
-            return jax.tree.map(lambda p, g: p + tr["lr_d"] * g, d,
-                                grad), obj               # ascent on eq. (2)
-
-        d, objs = jax.lax.scan(step, disc, (real_k, fakes))
-        qkey = jax.random.fold_in(jax.random.fold_in(round_key, SALT_QUANT),
-                                  k)
-        return quantize_upload(qkey, d, tr["quantize_bits"]), objs[-1]
-
-    uploads, objs = jax.lax.map(worker, (jnp.arange(k_workers), real))
-    if variant == "no_exchange":
-        disc = jax.tree.map(lambda u: u[0], uploads)
-    else:                                 # Algorithm 2, weights m_k
-        w = jnp.full((k_workers,), float(m)) / (k_workers * float(m))
-        disc = jax.tree.map(lambda u: jnp.tensordot(w, u, 1, precision=HI),
-                            uploads)
-
-    def server_step(g, j):
-        z = _shared_noise(round_key, j, big_m, cfg)
-        loss, grad = jax.value_and_grad(gen_objective)(g, disc, z, cfg,
-                                                       variant)
-        return jax.tree.map(lambda p, q: p - tr["lr_g"] * q, g, grad), loss
-
-    gen, losses = jax.lax.scan(server_step, gen, jnp.arange(n_g))
-    return {"gen": gen, "disc": disc}, (jnp.mean(objs), losses[-1])
+    disc, objs = jax.lax.scan(worker, jax.tree.map(jnp.zeros_like, disc),
+                              (jnp.arange(tr["workers"]), real,
+                               average_weights(tr)))
+    gen, loss = server_round(gen, disc, round_key, cfg, tr, variant)
+    return {"gen": gen, "disc": disc}, (jnp.mean(objs), loss)
 
 
 _PROTOCOL_KEYS = ("workers", "n_d", "n_g", "m_k", "M", "lr_d", "lr_g",
                   "quantize_bits")
+
+
+def statics(cfg: dict, traffic: dict):
+    """`reference_round`'s cfg_items and traffic_items: the family and
+    its model keys as loaded, and the protocol's keys."""
+    return (Static({"family": cfg["family"],
+                    **{k: cfg[k] for k in family(cfg).MODEL_KEYS}}),
+            tuple((k, traffic[k]) for k in _PROTOCOL_KEYS))
 
 
 @jax.jit
@@ -223,9 +278,7 @@ def run(cfg: dict, traffic: dict, params, shards, key, n_rounds: int,
         raise ValueError("the reference covers the proposed algorithm's "
                          "serial schedule with every worker scheduled")
     device = device or jax.devices()[0]
-    cfg_items = (("family", cfg["family"]),) + tuple(
-        (k, cfg[k]) for k in family(cfg).MODEL_KEYS)
-    tr_items = tuple((k, traffic[k]) for k in _PROTOCOL_KEYS)
+    cfg_items, tr_items = statics(cfg, traffic)
     k_workers, n_d, m = traffic["workers"], traffic["n_d"], traffic["m_k"]
     n_local = shards.shape[1]
     indices = jax.jit(sample_indices, static_argnums=(1, 2, 3, 4))

@@ -26,16 +26,16 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 # the benchmark's files
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", CELLS)
-def test_cell_files_load_and_name_known_metrics(name):
-    cell = spec.cell(name, BENCH)
+def check_cell(cell):
+    """What a cell's files give, whatever its model family."""
     assert cell.chips in (1, 4)
     assert set(cell.limits) == set(check.NUMBERS)
     assert all(0 < v < 1 for v in cell.limits.values())
     for key in ("workers", "n_d", "n_g", "m_k", "M", "layout",
                 "rounds_per_dispatch", "quantize_bits"):
         assert key in cell.traffic, key
-    assert cell.config["train_images"] // cell.traffic["workers"] > 0
+    family = spec.family_module(cell.config["family"], "reference")
+    assert family.samples(cell.config) // cell.traffic["workers"] > 0
     names = {m["name"] for m in cell.end_to_end}
     assert {"rounds_per_s", "setup_s"} <= names
     assert cell.per_layer, "every cell reports a per-layer metric"
@@ -44,6 +44,11 @@ def test_cell_files_load_and_name_known_metrics(name):
         assert m["moves"] in names
     fl = cell.flops_module()
     assert fl.round_flops(cell.config, cell.traffic, cell.chips)["total"] > 0
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_files_load_and_name_known_metrics(name):
+    check_cell(spec.cell(name, BENCH))
 
 
 def test_benchmark_names_the_files_it_uses():
